@@ -8,7 +8,8 @@ complete, ER p∈{0.2,0.4,0.6}) this prints, per ``gossip_impl`` backend:
   formulas the GossipSchedule lowering realizes), plus the schedule shape
   (number of ppermute matchings / rotations), and
 - with ``--measure``, measured wall time of the jitted inter-cluster mix
-  on an 8-fake-device host mesh.
+  on a mesh of every device JAX finds (at least 4; on a CPU host, start
+  it with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
 
 Asserts the headline claim: for every non-complete topology the sparse
 backends move strictly less traffic than the dense all-gather.
@@ -22,10 +23,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-if "--measure" in sys.argv:  # must precede the first jax import
-    os.environ.setdefault(
-        "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import numpy as np  # noqa: E402
 
@@ -49,22 +46,27 @@ def _case_name(name: str, kw) -> str:
 
 
 def measure_step_times(fl: FLConfig):
-    """Wall time of the jitted inter-cluster mix per backend, on an m=4,
-    dpc=2 geometry (R=8 replicas = the 8 fake host devices)."""
+    """Wall time of the jitted inter-cluster mix per backend, one replica
+    per device found: 2 devices per cluster from 8 devices up, else one
+    (R = every device, at least 4 so the ring has 4 clusters)."""
     import dataclasses
     import time
 
     import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
     from repro.core.cefedavg import make_w_schedule, mix
     from repro.core.gossip import apply_gossip
+    from repro.launch.mesh import make_replica_mesh
 
-    fl = dataclasses.replace(fl, num_clusters=4, devices_per_cluster=2)
-    mesh = Mesh(np.asarray(jax.devices()).reshape(8, 1), ("data", "model"))
-    R = fl.num_clusters * fl.devices_per_cluster
+    R = jax.device_count()
+    if R < 4:
+        raise SystemExit(f"--measure needs at least 4 devices, found {R}")
+    dpc = 2 if R >= 8 and R % 2 == 0 else 1
+    fl = dataclasses.replace(fl, num_clusters=R // dpc,
+                             devices_per_cluster=dpc)
+    mesh = make_replica_mesh(R)
     sched = make_w_schedule(fl)
     x = jnp.asarray(np.random.default_rng(0).normal(
         size=(R, 1 << 18)).astype(np.float32))
@@ -90,7 +92,7 @@ def measure_step_times(fl: FLConfig):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--measure", action="store_true",
-                    help="also time the jitted mix on 8 fake devices")
+                    help="also time the jitted mix on the devices found")
     args = ap.parse_args()
 
     print(f"{'topology':16s} {'impl':10s} {'matchings':>9s} "
@@ -131,7 +133,9 @@ def main():
                   f"{tr['per_replica_bits'] / 8e6:14.1f} "
                   f"{tr['total_bits'] / 8e6:9.1f} {ratio:8.2f}" + extra)
     if args.measure:
-        print("\nnote: step_ms is an 8-fake-device CPU host, where "
+        import jax
+        print(f"\nnote: step_ms ran on {jax.device_count()} "
+              f"{jax.devices()[0].platform} device(s); on a CPU host the "
               "collectives are memcpys — the bytes columns are what govern "
               "wall time on real interconnects (see core/runtime.py).")
     print("\nOK: sparse and ringweight move less traffic than the dense "

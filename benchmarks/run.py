@@ -30,6 +30,7 @@ import numpy as np  # noqa: E402
 
 from benchmarks.common import (Timer, make_data, make_sim,  # noqa: E402
                                paper_runtime, row, time_to_accuracy)
+from repro.compile_cache import use_compile_cache  # noqa: E402
 from repro.config import FLConfig  # noqa: E402
 
 ROUNDS = 10
@@ -553,6 +554,7 @@ BENCHES = {"fig2": fig2, "fig3": fig3, "fig4": fig4, "fig5": fig5,
 
 def main() -> None:
     import inspect
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="run the real FEMNIST CNN (slow on CPU)")

@@ -1208,6 +1208,7 @@ class FLSimulator:
         from repro.kernels import cold_codec
         codec, segs = self.store.codec, self._layout.segments
         shard = self._slab_sharding
+        encode, decode = cold_codec.row_codec(codec, segs, shard)
 
         # q_in/s_in are staged fresh every round and consumed only
         # here: donating them makes the forwarding scatter in-place
@@ -1219,7 +1220,7 @@ class FLSimulator:
             q = q_in.at[dst].set(q_prev[src], mode="drop")
             s = s_in.at[dst].set(s_prev[src], mode="drop")
             Y0 = refs[src_labels]
-            M0 = cold_codec.decode_rows(q, s, codec, segs)
+            M0 = decode(q, s)
             if shard is not None:
                 Y0 = jax.lax.with_sharding_constraint(Y0, shard)
                 M0 = jax.lax.with_sharding_constraint(M0, shard)
@@ -1230,7 +1231,7 @@ class FLSimulator:
         @jax.jit
         def post(Y, M, refs, upd, lanes):
             refs_new = jnp.where(upd[:, None], Y[lanes], refs)
-            q_out, s_out = cold_codec.encode_rows(M, codec, segs)
+            q_out, s_out = encode(M)
             return refs_new, q_out, s_out
 
         return pre, post

@@ -1,48 +1,19 @@
-"""Version-portable collectives for the sparse aggregation backends.
+"""Replica-axis helpers for the sparse aggregation backends.
 
-``jax.shard_map`` only exists as a top-level export (with a ``check_vma``
-kwarg) on newer JAX; the pinned 0.4.x line ships it as
-``jax.experimental.shard_map.shard_map`` (with ``check_rep``). Every sparse
-backend routes through :func:`shard_map` here so the version split lives in
-exactly one place.
-
-The helpers below also treat the mesh's replica axes (``pod`` × ``data``)
-as ONE flattened logical axis: JAX collectives accept a tuple of axis names,
-with the flat index being ``pod_idx * data_size + data_idx`` — exactly the
-replica numbering of ``ReplicaGeometry``. Working on the flat axis lets a
-single ``ppermute`` express any replica permutation, including multi-pod
-edge crossings, with no per-topology special cases.
+The backends run inside ``jax.shard_map`` bodies and treat the mesh's
+replica axes (``pod`` × ``data``) as ONE flattened logical axis: JAX
+collectives accept a tuple of axis names, with the flat index being
+``pod_idx * data_size + data_idx`` — exactly the replica numbering of
+``ReplicaGeometry``. Working on the flat axis lets a single ``ppermute``
+express any replica permutation, including multi-pod edge crossings,
+with no per-topology special cases.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any, Callable, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh
-
-
-def _resolve_shard_map() -> Tuple[Callable, str]:
-    """(shard_map callable, name of its replication-check kwarg)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # type: ignore
-    params = inspect.signature(fn).parameters
-    for kw in ("check_vma", "check_rep"):
-        if kw in params:
-            return fn, kw
-    return fn, ""
-
-
-_SHARD_MAP, _CHECK_KW = _resolve_shard_map()
-
-
-def shard_map(f: Callable, mesh: Mesh, in_specs: Any, out_specs: Any,
-              check: bool = False) -> Callable:
-    """``jax.shard_map`` on new JAX, ``jax.experimental.shard_map`` on old."""
-    kw = {_CHECK_KW: check} if _CHECK_KW else {}
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
 
 
 def replica_axis_names(mesh: Mesh) -> Tuple[str, ...]:

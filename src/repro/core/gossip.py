@@ -283,7 +283,8 @@ def apply_gossip(sched: GossipSchedule, params, specs, mesh: Mesh):
             sched, mesh, jax.tree.map(lambda x: x.astype(jnp.float32), p))
         return jax.tree.map(lambda x, o: o.astype(x.dtype), p, q)
 
-    return col.shard_map(body, mesh, (specs,), specs)(params)
+    return jax.shard_map(body, mesh=mesh, in_specs=(specs,), out_specs=specs,
+                         check_vma=False)(params)
 
 
 def group_mean_in_body(mesh: Mesh, p, groups):
@@ -328,7 +329,8 @@ def apply_cluster_mean(params, specs, mesh: Mesh, num_clusters: int,
             mesh, jax.tree.map(lambda x: x.astype(jnp.float32), p),
             num_clusters, devices_per_cluster)
         return jax.tree.map(lambda x, o: o.astype(x.dtype), p, q)
-    return col.shard_map(body, mesh, (specs,), specs)(params)
+    return jax.shard_map(body, mesh=mesh, in_specs=(specs,), out_specs=specs,
+                         check_vma=False)(params)
 
 
 def dense_mix_rows(W: jax.Array, x: jax.Array, mesh: Mesh) -> jax.Array:

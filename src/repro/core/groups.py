@@ -178,7 +178,8 @@ class GroupRegistry:
             q = self.mean_in_body(
                 jax.tree.map(lambda x: x.astype(jnp.float32), p), level)
             return jax.tree.map(lambda x, o: o.astype(x.dtype), p, q)
-        return col.shard_map(body, self.mesh, (specs,), specs)(params)
+        return jax.shard_map(body, mesh=self.mesh, in_specs=(specs,),
+                             out_specs=specs, check_vma=False)(params)
 
     def gossip(self, params, specs, level: int, pi: int,
                mode: str = "rounds"):

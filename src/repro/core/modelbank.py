@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.gossip_mix import FlatLayout, gossip_mix_rows
+from repro.kernels.ref import gossip_mix_rows_ref
 
 
 class ModelBank:
@@ -172,9 +173,16 @@ class ModelBank:
     def project(self, P):
         """Row-apply a rectangular (m, n) operator to the bank and
         materialize the resulting m models as a pytree — the edge-model
-        projection P of eq. 11 in one streaming pass."""
-        return self.layout.unflatten_stack(
-            gossip_mix_rows(jnp.asarray(P, jnp.float32), self.params))
+        projection P of eq. 11 in one streaming pass. A bank whose rows
+        live on several devices projects through an XLA matmul, which
+        the partitioner splits across them; a Pallas kernel cannot be
+        partitioned."""
+        P = jnp.asarray(P, jnp.float32)
+        if len(self.params.sharding.device_set) > 1:
+            out = gossip_mix_rows_ref(P, self.params)
+        else:
+            out = gossip_mix_rows(P, self.params)
+        return self.layout.unflatten_stack(out)
 
 
 # ---------------------------------------------------------------------------

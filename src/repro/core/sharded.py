@@ -599,7 +599,8 @@ class ShardedBankCEFedAvg(FLSimulator):
         nextra = 1 + nmats + (1 if adaptive else 0) + 1  # key+mats+td+mask
         in_specs = (row,) * nbank + (rep,) * nextra + (P(self._rspec),) * 2
         out_specs = (row,) * nbank
-        mapped = col.shard_map(body, mesh, in_specs, out_specs)
+        mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
 
         @functools.partial(jax.jit,
                            donate_argnums=(0, 1, 2) if with_res else (0, 1))

@@ -23,8 +23,12 @@ counts are small) with a uniform column-block grid inside each:
 - ``_absmax_kernel``   per-row |seg| max, accumulated across the column
                        grid in the revisited (rows, 1) output block;
 - ``_affine_*_kernel`` elementwise quantize/dequantize against the
-                       per-row scale block;
-- ``_cast_kernel``     the f16 encode/decode (pure dtype cast).
+                       per-row scale block.
+
+The f16 codec is a plain XLA ``astype`` on every backend: the IEEE cast
+is what the host codec computes, and Mosaic does not lower an f32<->f16
+cast inside a kernel (it refuses the packing on encode and the f16 load
+on decode).
 
 Dispatch follows the ``gossip_mix`` idiom: Pallas on TPU backends,
 the pure-jnp oracle (``kernels.ref.cold_encode_ref``/``cold_decode_ref``)
@@ -33,6 +37,7 @@ the mode the tier-1 tests validate against the host codec.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -100,10 +105,6 @@ def _affine_dec_kernel(q_ref, s_ref, o_ref):
     o_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...]
 
 
-def _cast_kernel(x_ref, o_ref):
-    o_ref[...] = x_ref[...].astype(o_ref.dtype)
-
-
 # -- per-segment pallas_call wrappers ----------------------------------------
 
 def _segment_absmax(x, interpret: bool):
@@ -124,23 +125,20 @@ def _segment_absmax(x, interpret: bool):
 
 def _elementwise(kernel, x, scale, out_dtype, interpret: bool):
     """Run an elementwise (x, per-row scale) -> out kernel over the
-    column-block grid; ``scale=None`` drops the scale operand (casts)."""
+    column-block grid."""
     S, w = x.shape
     Sp, wp = _ceil_to(S, _BLK_ROWS), _ceil_to(w, _BLK_COLS)
     xspec = pl.BlockSpec((_BLK_ROWS, _BLK_COLS), lambda i, j: (i, j))
-    args, in_specs = [_pad2(x, Sp, wp)], [xspec]
-    if scale is not None:
-        # pad rows with scale 1 so padding lanes never divide by zero
-        args.append(_pad2(scale[:, None], Sp, 1, value=1.0))
-        in_specs.append(pl.BlockSpec((_BLK_ROWS, 1), lambda i, j: (i, 0)))
+    # pad rows with scale 1 so padding lanes never divide by zero
+    scale = _pad2(scale[:, None], Sp, 1, value=1.0)
     out = pl.pallas_call(
         kernel,
         grid=(Sp // _BLK_ROWS, wp // _BLK_COLS),
-        in_specs=in_specs,
+        in_specs=[xspec, pl.BlockSpec((_BLK_ROWS, 1), lambda i, j: (i, 0))],
         out_specs=xspec,
         out_shape=jax.ShapeDtypeStruct((Sp, wp), out_dtype),
         interpret=interpret,
-    )(*args)
+    )(_pad2(x, Sp, wp), scale)
     return out[:S, :w]
 
 
@@ -161,12 +159,8 @@ def encode_rows(rows, codec: str, segments, *, use_pallas=None,
     S = rows.shape[0]
     if codec == "f32":
         return rows, jnp.zeros((S, 0), jnp.float32)
-    if not _use_pallas(use_pallas) and not interpret:
+    if codec == "f16" or (not _use_pallas(use_pallas) and not interpret):
         return _ref.cold_encode_ref(rows, codec, segments)
-    if codec == "f16":
-        return (_elementwise(_cast_kernel, rows, None, jnp.float16,
-                             interpret),
-                jnp.zeros((S, 0), jnp.float32))
     qs, ss = [], []
     for off, size in segments:
         seg = rows[:, off:off + size]
@@ -185,14 +179,30 @@ def decode_rows(q, scale, codec: str, segments, *, use_pallas=None,
     client's momentum, which is how the streamed page-in materializes
     first-touch lanes without a host round trip."""
     assert codec in CODECS, codec
-    if codec == "f32":
-        return q.astype(jnp.float32)
-    if not _use_pallas(use_pallas) and not interpret:
+    if codec in ("f32", "f16") or (not _use_pallas(use_pallas)
+                                   and not interpret):
         return _ref.cold_decode_ref(q, scale, codec, segments)
-    if codec == "f16":
-        return _elementwise(_cast_kernel, q, None, jnp.float32, interpret)
     outs = []
     for j, (off, size) in enumerate(segments):
         outs.append(_elementwise(_affine_dec_kernel, q[:, off:off + size],
                                  scale[:, j], jnp.float32, interpret))
     return jnp.concatenate(outs, axis=1)
+
+
+def row_codec(codec: str, segments, sharding=None, *, use_pallas=None):
+    """``(encode, decode)`` of :func:`encode_rows`/:func:`decode_rows`
+    for (S, T) slabs. Given a row ``sharding`` (a ``NamedSharding`` whose
+    spec splits the rows), both run on each row shard under
+    ``jax.shard_map``: rows are coded independently, and XLA cannot
+    partition a Pallas kernel across devices."""
+    enc = functools.partial(encode_rows, codec=codec, segments=segments,
+                            use_pallas=use_pallas)
+    dec = functools.partial(decode_rows, codec=codec, segments=segments,
+                            use_pallas=use_pallas)
+    if sharding is None:
+        return enc, dec
+    rows = sharding.spec
+    return (jax.shard_map(enc, mesh=sharding.mesh, in_specs=rows,
+                          out_specs=(rows, rows), check_vma=False),
+            jax.shard_map(dec, mesh=sharding.mesh, in_specs=(rows, rows),
+                          out_specs=rows, check_vma=False))

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × input shape × mesh)
 combination against ShapeDtypeStruct inputs (no allocation) and extract the
 roofline terms from the compiled artifacts.
@@ -23,6 +20,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from typing import Any, Dict
@@ -319,6 +317,9 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main():
+    # the production meshes need 512 host devices; the CPU backend reads
+    # this flag once, when it first starts, which is after this line
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default=None)
     ap.add_argument("--shape", choices=sorted(INPUT_SHAPES), default=None)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import save_checkpoint
+from repro.compile_cache import use_compile_cache
 from repro.config import ExperimentConfig, FLConfig, TrainConfig
 from repro.configs import ARCHS, get_model_config
 from repro.core.sharded import ShardedCEFedAvg
@@ -33,6 +34,7 @@ from repro.launch.mesh import make_mesh
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="qwen2-0.5b")
     ap.add_argument("--reduced", action="store_true",

@@ -189,6 +189,25 @@ def test_row_shards_and_donation(mesh):
     assert sb.bank.params.sharding == sb._row_sharding
 
 
+def test_edge_projection_of_sharded_bank_is_xla(mesh, monkeypatch):
+    """A row-sharded bank projects its edge models through XLA: the
+    Pallas mixing kernel cannot be partitioned across the row shards
+    (on a TPU mesh it would refuse to compile)."""
+    from repro.core import modelbank
+    ref, sb = _pair(_FL, mesh)
+    ref.step_round()
+    sb.step_round()
+
+    def no_kernel(*a, **k):
+        raise AssertionError("Pallas path on a sharded bank")
+    monkeypatch.setattr(modelbank, "gossip_mix_rows", no_kernel)
+    got = sb.edge_models()
+    monkeypatch.undo()
+    want = ref.edge_models()
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert _maxdiff(g, w) < ATOL
+
+
 def test_mesh_guards():
     """Row-per-device and no-tensor-parallel preconditions are enforced."""
     mesh = make_replica_mesh(NDEV)

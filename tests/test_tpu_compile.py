@@ -1,0 +1,137 @@
+"""Compile-only checks of the main path's Pallas kernels for a TPU v5e.
+
+Nothing here runs on a chip. Each test lowers a kernel at the width the
+system runs it and compiles it with the TPU compiler for one chip of a
+described ``v5e:2x2`` topology: the compiler refuses what Mosaic cannot
+lower (an unaligned slice, an f16 cast inside a kernel, too much VMEM)
+and what does not fit the chip's memory, which interpret mode never
+shows. Shapes:
+
+- ``gossip_mix_flat`` at the paper cell's bank, n = 64 rows of the
+  FEMNIST CNN (T = 6,603,710): the square mixing operator and the
+  (64 -> 8) edge-model projection;
+- ``cold_codec`` encode/decode at the population cell's wide MLP
+  (64 -> 2048 -> 32, T = 198,688) on a 16-row slab, int8 and f16, on
+  one chip and row-sharded over the four chips (the sharded streamed
+  engine's slab).
+
+The topology is described inside a module fixture, never at import:
+the TPU library may be loaded by one process at a time, and only the
+worker that runs this file loads it.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
+
+from repro.configs import femnist_cnn
+from repro.kernels import cold_codec
+from repro.kernels.gossip_mix import FlatLayout, gossip_mix_flat
+from repro.models.cnn import init_mlp_classifier
+
+#: HBM of one TPU v5e chip
+V5E_HBM_BYTES = 16 * 2 ** 30
+N_PAPER = 64
+SLAB_ROWS = 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mlp_segments():
+    one = init_mlp_classifier(jax.random.PRNGKey(0), 64, 2048, 32)
+    layout = FlatLayout.for_tree(one)
+    assert layout.total == 198_688
+    return layout.segments
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _peak_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("k", [N_PAPER, 8], ids=["square", "edge_projection"])
+def test_gossip_mix_flat_compiles_at_paper_width(one_chip, k):
+    """The mixing kernel at the paper cell's (64, 6,603,710) bank."""
+    T = femnist_cnn.PARAMS
+    W = jax.ShapeDtypeStruct((N_PAPER, k), jnp.float32, sharding=one_chip)
+    Y = jax.ShapeDtypeStruct((N_PAPER, T), jnp.float32, sharding=one_chip)
+    compiled = _compile(gossip_mix_flat, W, Y)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _peak_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("codec", ["int8", "f16"])
+def test_cold_codec_compiles_at_population_width(one_chip, mlp_segments,
+                                                 codec):
+    """Device encode + decode of a wide-MLP slab. int8 runs the Pallas
+    absmax/affine kernels; f16 is an XLA cast that Mosaic never sees."""
+    T = 198_688
+    nseg = len(mlp_segments) if codec == "int8" else 0
+    qdt = jnp.int8 if codec == "int8" else jnp.float16
+    rows = jax.ShapeDtypeStruct((SLAB_ROWS, T), jnp.float32,
+                                sharding=one_chip)
+    q = jax.ShapeDtypeStruct((SLAB_ROWS, T), qdt, sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((SLAB_ROWS, nseg), jnp.float32,
+                                 sharding=one_chip)
+    enc = _compile(lambda r: cold_codec.encode_rows(
+        r, codec, mlp_segments, use_pallas=True), rows)
+    dec = _compile(lambda q, s: cold_codec.decode_rows(
+        q, s, codec, mlp_segments, use_pallas=True), q, scale)
+    for compiled in (enc, dec):
+        hlo = compiled.as_text()
+        assert ("tpu_custom_call" in hlo) == (codec == "int8")
+        assert _peak_bytes(compiled) < V5E_HBM_BYTES
+    out_q, out_s = jax.eval_shape(lambda r: cold_codec.encode_rows(
+        r, codec, mlp_segments, use_pallas=True), rows)
+    assert out_q.dtype == np.dtype(qdt) and out_s.shape == scale.shape
+
+
+def test_cold_codec_compiles_row_sharded_on_four_chips(topo, mlp_segments):
+    """``row_codec`` over a slab row-sharded on the 2x2 mesh: each chip
+    runs the int8 kernels on its own rows (an unwrapped Pallas kernel
+    would be refused: Mosaic kernels cannot be auto-partitioned)."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"))
+    rows = NamedSharding(mesh, PartitionSpec("data", None))
+    enc, dec = cold_codec.row_codec("int8", mlp_segments, rows,
+                                    use_pallas=True)
+    S = jax.ShapeDtypeStruct
+    slab = (SLAB_ROWS, 198_688)
+    for compiled in (
+            _compile(enc, S(slab, jnp.float32, sharding=rows)),
+            _compile(dec, S(slab, jnp.int8, sharding=rows),
+                     S((SLAB_ROWS, len(mlp_segments)), jnp.float32,
+                       sharding=rows))):
+        assert "tpu_custom_call" in compiled.as_text()
+        assert _peak_bytes(compiled) < V5E_HBM_BYTES
