@@ -38,7 +38,6 @@ time-varying π_t — without touching engine code.
 from __future__ import annotations
 
 import functools
-import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
 
@@ -51,6 +50,7 @@ from repro.core import program as prg
 from repro.core import topology as topo
 from repro.core.modelbank import (ModelBank, bucket_for, cohort_buckets,
                                   compact_plan)
+from repro.core.spans import span
 from repro.kernels.gossip_mix import FlatLayout, gossip_mix_rows
 
 
@@ -124,6 +124,53 @@ def mix(W, params):
         out = jnp.tensordot(Wj, leaf.astype(jnp.float32), axes=[[1], [0]])
         return out.astype(leaf.dtype)
     return jax.tree.map(one, params)
+
+
+def paging_fns(codec: str, segments, sharding=None):
+    """The pipelined streamed round's pre/post jits. The CORE round stays
+    the serial streamed round's own compiled lowering (``_get_round``) — f32
+    bit-identity holds by construction because the same executable sees
+    the same input bits. pre/post only gather, scatter and run the cold
+    codec, all bit-exact at f32:
+
+    - pre (``named_scope("page_in")``): page-in on device — params from
+      the resident cluster references, momentum decoded from the staged
+      encoded rows after scattering in forwarded rows (clients sampled
+      in consecutive rounds, whose newest momentum exists only as the
+      previous round's device-side page-out);
+    - post (``named_scope("page_out")``): page-out on device — fold each
+      updated cluster's synced lane into the references, encode the
+      slab's momentum so the D2H transfer carries codec-width bytes."""
+    from repro.kernels import cold_codec
+    encode, decode = cold_codec.row_codec(codec, segments, sharding)
+
+    # q_in/s_in are staged fresh every round and consumed only
+    # here: donating them makes the forwarding scatter in-place
+    # (CPU ignores donation and warns, so only donate off-CPU)
+    donate = (() if jax.default_backend() == "cpu" else (2, 3))
+
+    @functools.partial(jax.jit, donate_argnums=donate)
+    def pre(refs, src_labels, q_in, s_in, q_prev, s_prev, src, dst):
+        with jax.named_scope("page_in"):
+            q = q_in.at[dst].set(q_prev[src], mode="drop")
+            s = s_in.at[dst].set(s_prev[src], mode="drop")
+            Y0 = refs[src_labels]
+            M0 = decode(q, s)
+            if sharding is not None:
+                Y0 = jax.lax.with_sharding_constraint(Y0, sharding)
+                M0 = jax.lax.with_sharding_constraint(M0, sharding)
+        return Y0, M0
+
+    # refs must NOT be donated: the previous round's pending
+    # page-out still holds this buffer until the next drain
+    @jax.jit
+    def post(Y, M, refs, upd, lanes):
+        with jax.named_scope("page_out"):
+            refs_new = jnp.where(upd[:, None], Y[lanes], refs)
+            q_out, s_out = encode(M)
+        return refs_new, q_out, s_out
+
+    return pre, post
 
 
 # ---------------------------------------------------------------------------
@@ -264,10 +311,9 @@ class FLSimulator:
         assert not self._pipeline or self._streamed, \
             "pipeline=True overlaps *paging* with compute — it requires " \
             "the streamed engine (streaming=True or a population scenario)"
-        # cumulative host seconds spent paging (staging/fetch/commit/
-        # drain); clock.run_wall_clock splits eval windows into
-        # page_s/compute_s from deltas of this counter
-        self._page_seconds = 0.0
+        # cumulative host seconds per span of ``step_round``
+        # (``core/spans.py``: round, assemble, dispatch, page.*)
+        self.host_seconds: Dict[str, float] = {}
         self.last_bucket = n   # cohort capacity used by the latest round
         # -- round schedule (RoundProgram IR) -------------------------------
         # every engine round is a lowering of a RoundProgram; the static
@@ -307,6 +353,18 @@ class FLSimulator:
         self._static_labels = self.labels.copy()
         self.key = jax.random.PRNGKey(seed + 1)
         self._eval_fn = self._build_eval()
+
+    def _span(self, name: str):
+        """A ``repro.<name>`` host span counted in ``host_seconds``."""
+        return span(self.host_seconds, name)
+
+    @property
+    def _page_seconds(self) -> float:
+        """Cumulative host seconds spent paging (the ``page.*`` spans:
+        wait, commit, stage); ``clock.run_wall_clock`` splits eval
+        windows into page_s/compute_s from deltas of this counter."""
+        return sum(v for k, v in self.host_seconds.items()
+                   if k.startswith("page."))
 
     def _make_bank(self, one, n: int, with_residual: bool) -> ModelBank:
         """Bank construction hook: the single-process engine broadcasts
@@ -552,9 +610,10 @@ class FLSimulator:
     def _train_scan(local_step, Y, M, key, op):
         """τ local steps of one block: scan over the block's step keys
         (plus the step index when the op is adaptive)."""
-        keys = jax.random.split(key, op.tau)
-        xs_ = (keys, jnp.arange(op.tau)) if op.adaptive else keys
-        (Y, M), _ = jax.lax.scan(local_step, (Y, M), xs_)
+        with jax.named_scope("local_steps"):
+            keys = jax.random.split(key, op.tau)
+            xs_ = (keys, jnp.arange(op.tau)) if op.adaptive else keys
+            (Y, M), _ = jax.lax.scan(local_step, (Y, M), xs_)
         return Y, M
 
     def _lower_flat(self, program: prg.RoundProgram,
@@ -941,51 +1000,75 @@ class FLSimulator:
         the capacity used). Returns the ``RoundPlan`` (or None without a
         scenario); ``last_program`` records the executed program so
         callers — e.g. the wall-clock harness in core/clock.py — can
-        charge the cohort per op."""
-        if self._streamed:
+        charge the cohort per op. The call is the ``repro.round`` host
+        span; its parts are the ``assemble``, ``dispatch`` and
+        ``page.*`` spans (``core/spans.py``)."""
+        with self._span("round"):
+            if not self._streamed:
+                return self._step_round_resident()
             if self._pipeline:
                 return self._step_round_streamed_pipelined()
             return self._step_round_streamed()
-        if self.engine is not None:
-            plan = self.engine.step()
-            self.labels = plan.labels
-            mask_np = plan.mask
-        else:
-            plan = None
-            mask_np = None
+
+    def _next_program(self, plan) -> prg.RoundProgram:
+        """Advance the round counter and pick this round's program."""
         r = self.round_index
         self.round_index += 1
         program = (self._schedule_fn(r, plan)
                    if self._schedule_fn is not None else self._canonical)
         self.last_program = program
-        mask = (jnp.asarray(mask_np, jnp.float32)
-                if mask_np is not None else self._full_mask)
-        self.key, k = jax.random.split(self.key)
-        if self.bank is None:
-            args = self._resolve_args(program, plan, fuse=False)
-            fn = self._get_round("legacy", program)
-            self._params, self._mom, self._residual = fn(
-                self._params, self._mom, self._residual, k, args, mask)
-            return plan
+        return program
+
+    def _step_round_resident(self):
+        """One round of the resident engines (legacy, flat, compact)."""
         b = self.bank
-        args = self._resolve_args(program, plan, fuse=True)
-        k_active = b.n if mask_np is None else int(mask_np.sum())
-        # 0 < k_active: a fully-dark fault round (empty cohort) cannot
-        # compact — it runs the flat path, where the zero mask freezes
-        # training and the fault-gated operators are the identity
-        if (not program.has_upload and 0 < k_active < b.n
-                and self._compact_enabled):
-            cp = compact_plan(mask_np, self._buckets)
-            self.last_bucket = cp.k_pad
-            fn = self._get_round("compact", program)
-            b.params, b.mom = fn(b.params, b.mom, k, jnp.asarray(cp.idx),
-                                 jnp.asarray(cp.lane), args)
-            return plan
-        self.last_bucket = b.n
-        fn = self._get_round("flat", program)
-        b.params, b.mom, b.residual = fn(b.params, b.mom, b.residual, k,
-                                         args, mask)
+        with self._span("assemble"):
+            if self.engine is not None:
+                plan = self.engine.step()
+                self.labels = plan.labels
+                mask_np = plan.mask
+            else:
+                plan = None
+                mask_np = None
+            program = self._next_program(plan)
+            mask = (jnp.asarray(mask_np, jnp.float32)
+                    if mask_np is not None else self._full_mask)
+            self.key, k = jax.random.split(self.key)
+            args = self._resolve_args(program, plan, fuse=b is not None)
+            cp = None
+            if b is not None:
+                k_active = b.n if mask_np is None else int(mask_np.sum())
+                # 0 < k_active: a fully-dark fault round (empty cohort)
+                # cannot compact — it runs the flat path, where the zero
+                # mask freezes training and the fault-gated operators are
+                # the identity
+                if (not program.has_upload and 0 < k_active < b.n
+                        and self._compact_enabled):
+                    cp = compact_plan(mask_np, self._buckets)
+                self.last_bucket = b.n if cp is None else cp.k_pad
+        with self._span("dispatch"):
+            if b is None:
+                fn = self._get_round("legacy", program)
+                self._params, self._mom, self._residual = fn(
+                    self._params, self._mom, self._residual, k, args, mask)
+            elif cp is not None:
+                fn = self._get_round("compact", program)
+                b.params, b.mom = fn(b.params, b.mom, k,
+                                     jnp.asarray(cp.idx),
+                                     jnp.asarray(cp.lane), args)
+            else:
+                fn = self._get_round("flat", program)
+                b.params, b.mom, b.residual = fn(
+                    b.params, b.mom, b.residual, k, args, mask)
         return plan
+
+    def _check_streamed(self, program: prg.RoundProgram) -> None:
+        assert not program.has_upload, \
+            "streamed rounds reject upload programs (EF residual and " \
+            "DP noise are per-device state the store does not page)"
+        assert program.mask_renorm, \
+            "streamed rounds need mask-renormalized operators — " \
+            "unrenormalized rows weight absent cold members"
 
     def _step_round_streamed(self):
         """One streamed global round: page in the working set, run the
@@ -1007,72 +1090,65 @@ class FLSimulator:
         only under visit mobility + full sampling) keeps a stale
         reference for the round."""
         from repro.core.modelbank import ModelBank as MB
+        from repro.core.scenario import RoundPlan, make_masked_w
         st = self.store
         m = self.fl.num_clusters
-        if self.engine is not None:
-            plan = self.engine.step()
-        else:
-            plan = None
-        r = self.round_index
-        self.round_index += 1
-        program = (self._schedule_fn(r, plan)
-                   if self._schedule_fn is not None else self._canonical)
-        self.last_program = program
-        assert not program.has_upload, \
-            "streamed rounds reject upload programs (EF residual and " \
-            "DP noise are per-device state the store does not page)"
-        assert program.mask_renorm, \
-            "streamed rounds need mask-renormalized operators — " \
-            "unrenormalized rows weight absent cold members"
-        ws = self._working_set(plan)
-        if self.pop is None:
-            self.labels = ws["labels_now"]
-        k, S = ws["k"], ws["S"]
-        clients, ws_labels = ws["clients"], ws["ws_labels"]
-        H_t = self._scenario_h(plan)
-        from repro.core.scenario import RoundPlan, make_masked_w
-        W_i, W_e = make_masked_w(self.fl, ws_labels, ws["mask_slab"], H_t)
-        splan = RoundPlan(r, m, ws_labels, ws["mask_slab"], W_i, W_e,
-                          fault=ws["fault"], H_eff=ws["h_eff"])
-        args = self._resolve_args(program, splan, fuse=True)
+        with self._span("assemble"):
+            plan = self.engine.step() if self.engine is not None else None
+            r = self.round_index
+            program = self._next_program(plan)
+            self._check_streamed(program)
+            ws = self._working_set(plan)
+            if self.pop is None:
+                self.labels = ws["labels_now"]
+            k, S = ws["k"], ws["S"]
+            clients, ws_labels = ws["clients"], ws["ws_labels"]
+            H_t = self._scenario_h(plan)
+            W_i, W_e = make_masked_w(self.fl, ws_labels, ws["mask_slab"],
+                                     H_t)
+            splan = RoundPlan(r, m, ws_labels, ws["mask_slab"], W_i, W_e,
+                              fault=ws["fault"], H_eff=ws["h_eff"])
+            args = self._resolve_args(program, splan, fuse=True)
         # page-in: params from each lane's last-sync cluster reference,
         # momentum decoded for the trainers only (cold lanes never step)
-        t0 = time.perf_counter()
-        params_rows = st.cluster_params[ws["src_labels"]]
-        mom_rows = np.zeros((S, self._layout.total), np.float32)
-        if k:
-            mom_rows[:k] = st.fetch(clients[:k])
-        slab = MB.from_rows(self._layout, params_rows, mom_rows,
-                            sharding=self._slab_sharding)
-        self._page_seconds += time.perf_counter() - t0
-        self.key, k_ = jax.random.split(self.key)
-        fn = self._get_round(
-            "streamed_pop" if self.pop is not None else "streamed",
-            program)
-        Y, M = fn(slab.params, slab.mom, k_,
-                  jnp.asarray(ws["didx"], jnp.int32),
-                  jnp.asarray(clients, jnp.int32),
-                  jnp.asarray(ws["lane"]), args)
+        with self._span("page.stage"):
+            params_rows = st.cluster_params[ws["src_labels"]]
+            mom_rows = np.zeros((S, self._layout.total), np.float32)
+            if k:
+                mom_rows[:k] = st.fetch(clients[:k])
+            slab = MB.from_rows(self._layout, params_rows, mom_rows,
+                                sharding=self._slab_sharding)
+        with self._span("dispatch"):
+            self.key, k_ = jax.random.split(self.key)
+            fn = self._get_round(
+                "streamed_pop" if self.pop is not None else "streamed",
+                program)
+            Y, M = fn(slab.params, slab.mom, k_,
+                      jnp.asarray(ws["didx"], jnp.int32),
+                      jnp.asarray(clients, jnp.int32),
+                      jnp.asarray(ws["lane"]), args)
+        # the wait for the round's compute is no paging: page.wait
+        # starts once the slab is ready
         jax.block_until_ready((Y, M))
-        t0 = time.perf_counter()
-        Yh = np.asarray(jax.device_get(Y), np.float32)
-        Mh = np.asarray(jax.device_get(M), np.float32)
+        with self._span("page.wait"):
+            Yh = np.asarray(jax.device_get(Y), np.float32)
+            Mh = np.asarray(jax.device_get(M), np.float32)
         # page-out: last lane of each cluster (representatives win over
         # participants by position) carries the synced reference
-        fault = ws["fault"]
-        ref_lane = np.full(m, -1, np.int64)
-        ref_lane[ws_labels] = np.arange(S)
-        down = (fault.cluster_down if fault is not None else None)
-        refs = st.cluster_params.copy()
-        for c in range(m):
-            j = int(ref_lane[c])
-            if j < 0 or (down is not None and down[c]):
-                continue  # no working-set lane / dark cluster: stale ref
-            refs[c] = Yh[j]
-        st.update_clusters(refs)
-        if k:
-            st.commit(clients[:k], Mh[:k])
-        self._page_seconds += time.perf_counter() - t0
+        with self._span("page.commit"):
+            fault = ws["fault"]
+            ref_lane = np.full(m, -1, np.int64)
+            ref_lane[ws_labels] = np.arange(S)
+            down = (fault.cluster_down if fault is not None else None)
+            refs = st.cluster_params.copy()
+            for c in range(m):
+                j = int(ref_lane[c])
+                if j < 0 or (down is not None and down[c]):
+                    continue  # no working-set lane / dark cluster: stale
+                refs[c] = Yh[j]
+            st.update_clusters(refs)
+            if k:
+                st.commit(clients[:k], Mh[:k])
         if self.pop is None:
             # next round's page-in reads the reference of the cluster a
             # device sat in NOW: the trailing boundary synced every row
@@ -1190,52 +1266,6 @@ class FLSimulator:
                 return False
         return True
 
-    def _make_pipe_helpers(self):
-        """The pipelined round's pre/post jits. The CORE round stays the
-        serial driver's own compiled lowering (``_get_round``) — f32
-        bit-identity holds by construction because the same executable
-        sees the same input bits. pre/post only gather, scatter and run
-        the cold codec, all bit-exact at f32:
-
-        - pre: page-in on device — params from the resident cluster
-          references, momentum decoded from the staged encoded rows
-          after scattering in forwarded rows (clients sampled in
-          consecutive rounds, whose newest momentum exists only as the
-          previous round's device-side page-out);
-        - post: page-out on device — fold each updated cluster's synced
-          lane into the references, encode the slab's momentum so the
-          D2H transfer carries codec-width bytes."""
-        from repro.kernels import cold_codec
-        codec, segs = self.store.codec, self._layout.segments
-        shard = self._slab_sharding
-        encode, decode = cold_codec.row_codec(codec, segs, shard)
-
-        # q_in/s_in are staged fresh every round and consumed only
-        # here: donating them makes the forwarding scatter in-place
-        # (CPU ignores donation and warns, so only donate off-CPU)
-        donate = (() if jax.default_backend() == "cpu" else (2, 3))
-
-        @functools.partial(jax.jit, donate_argnums=donate)
-        def pre(refs, src_labels, q_in, s_in, q_prev, s_prev, src, dst):
-            q = q_in.at[dst].set(q_prev[src], mode="drop")
-            s = s_in.at[dst].set(s_prev[src], mode="drop")
-            Y0 = refs[src_labels]
-            M0 = decode(q, s)
-            if shard is not None:
-                Y0 = jax.lax.with_sharding_constraint(Y0, shard)
-                M0 = jax.lax.with_sharding_constraint(M0, shard)
-            return Y0, M0
-
-        # refs must NOT be donated: the previous round's pending
-        # page-out still holds this buffer until the next drain
-        @jax.jit
-        def post(Y, M, refs, upd, lanes):
-            refs_new = jnp.where(upd[:, None], Y[lanes], refs)
-            q_out, s_out = encode(M)
-            return refs_new, q_out, s_out
-
-        return pre, post
-
     def _stage_pipelined(self, plan, r: int):
         """Stage round ``r``'s page-in: assemble its working set, gather
         the cohort's *encoded* cold rows (commits ≤ r-2 from the store;
@@ -1246,27 +1276,53 @@ class FLSimulator:
         qc, sc = self.store.fetch_encoded(ws["cohort"])
         # rep/pad lanes page in zero momentum, exactly like the serial
         # driver's zero-fill beyond [:k] (zero q + zero scale decode to
-        # exact zeros under every codec). The host buffers are cached
-        # per bucket — device_put/asarray below copies them out, so the
-        # next stage may safely overwrite; only [k:] needs re-zeroing.
+        # exact zeros under every codec). The host buffers are cached,
+        # two per bucket used in turn: a put may read its host buffer
+        # until the round that consumes it has run (the CPU backend
+        # aliases it outright), and the buffer staged two rounds back
+        # belongs to a round the previous drain has already landed.
+        # Only [k:] needs re-zeroing.
         bufs = getattr(self, "_stage_bufs", None)
         if bufs is None:
             bufs = self._stage_bufs = {}
         if S not in bufs:
-            bufs[S] = (np.zeros((S, self._layout.total), qc.dtype),
-                       np.zeros((S, sc.shape[1]), np.float32))
-        q, s = bufs[S]
+            bufs[S] = [(np.zeros((S, self._layout.total), qc.dtype),
+                        np.zeros((S, sc.shape[1]), np.float32))
+                       for _ in range(2)]
+        bufs[S].reverse()
+        q, s = bufs[S][0]
         q[:k] = qc
         q[k:] = 0
         s[:k] = sc
         s[k:] = 0
-        if self._slab_sharding is not None:
-            ws["q"] = jax.device_put(q, self._slab_sharding)
-            ws["s"] = jax.device_put(s, self._slab_sharding)
-        else:
-            ws["q"], ws["s"] = jnp.asarray(q), jnp.asarray(s)
+        ws["q"], ws["s"] = jax.device_put((q, s), self._slab_sharding)
         ws["plan"], ws["r"] = plan, r
         return ws
+
+    def _land_pending(self):
+        """Host copies of the in-flight page-out (None if there is none):
+        blocks on the async D2H of the last dispatched round."""
+        p = getattr(self, "_pipe", None)
+        if not p or p.get("pending") is None:
+            return None
+        pend, p["pending"] = p["pending"], None
+        k = pend["k"]
+        landed = {"cohort": pend["cohort"], "k": k,
+                  "refs": np.asarray(pend["refs"], np.float32)}
+        if k:
+            landed["q"] = np.asarray(pend["q"])[:k]
+            landed["s"] = np.asarray(pend["s"], np.float32)[:k]
+        return landed
+
+    def _commit_landed(self, landed) -> None:
+        """Commit a landed page-out's encoded momentum to the store and
+        mirror its cluster references."""
+        if landed is None:
+            return
+        st = self.store
+        st.update_clusters(landed["refs"])
+        if landed["k"]:
+            st.commit_encoded(landed["cohort"], landed["q"], landed["s"])
 
     def _drain_pipeline(self):
         """Land the in-flight page-out (if any) in the host store:
@@ -1275,17 +1331,7 @@ class FLSimulator:
         Called by the next round (overlapped by that round's compute)
         and by every store reader — eval, checkpoint capture — so
         observable host state is always round-complete."""
-        p = getattr(self, "_pipe", None)
-        if not p or p.get("pending") is None:
-            return
-        pend, p["pending"] = p["pending"], None
-        st = self.store
-        st.update_clusters(np.asarray(pend["refs"], np.float32))
-        k = pend["k"]
-        if k:
-            st.commit_encoded(pend["cohort"],
-                              np.asarray(pend["q"])[:k],
-                              np.asarray(pend["s"], np.float32)[:k])
+        self._commit_landed(self._land_pending())
 
     def _step_round_streamed_pipelined(self):
         """One overlapped streamed round (ISSUE 10 tentpole).
@@ -1304,7 +1350,9 @@ class FLSimulator:
         in both rounds t and t+1 get their newest momentum forwarded
         on device from round t's encoded page-out — covering exactly
         the missing delta. The store itself is only read by staging,
-        never by the round, so eval/checkpoint drains stay cheap."""
+        never by the round, so eval/checkpoint drains stay cheap. The
+        first round (and the first after a restore) stages its own
+        page-in from the peeked plan, as every later round's is."""
         from repro.core.scenario import RoundPlan, make_masked_w
         st = self.store
         m = self.fl.num_clusters
@@ -1312,98 +1360,93 @@ class FLSimulator:
             self._pipe = {"refs": None, "pending": None,
                           "staged": None, "prev": None}
         if self._pipe_fns is None:
-            self._pipe_fns = self._make_pipe_helpers()
+            self._pipe_fns = paging_fns(st.codec, self._layout.segments,
+                                        self._slab_sharding)
         p = self._pipe
         if p["refs"] is None:
             p["refs"] = jnp.asarray(st.cluster_params, jnp.float32)
         pre_fn, post_fn = self._pipe_fns
-        plan = self.engine.step() if self.engine is not None else None
-        r = self.round_index
-        self.round_index += 1
-        program = (self._schedule_fn(r, plan)
-                   if self._schedule_fn is not None else self._canonical)
-        self.last_program = program
-        assert not program.has_upload, \
-            "streamed rounds reject upload programs (EF residual and " \
-            "DP noise are per-device state the store does not page)"
-        assert program.mask_renorm, \
-            "streamed rounds need mask-renormalized operators — " \
-            "unrenormalized rows weight absent cold members"
-        staged, p["staged"] = p["staged"], None
-        if staged is not None:
-            assert staged["r"] == r and \
-                self._plans_match(staged["plan"], plan), \
+        if p["staged"] is None:
+            with self._span("page.stage"):
+                p["staged"] = self._stage_pipelined(self._peek_plan(),
+                                                    self.round_index)
+        with self._span("assemble"):
+            plan = self.engine.step() if self.engine is not None else None
+            r = self.round_index
+            program = self._next_program(plan)
+            self._check_streamed(program)
+            ws, p["staged"] = p["staged"], None
+            assert ws["r"] == r and self._plans_match(ws["plan"], plan), \
                 "prefetched plan diverged from the engine's real draw " \
                 "(engine state was perturbed between rounds)"
-            ws = staged
-        else:
-            # cold start (first round / right after restore): stage now
-            t0 = time.perf_counter()
-            ws = self._stage_pipelined(plan, r)
-            self._page_seconds += time.perf_counter() - t0
-        if self.pop is None:
-            self.labels = ws["labels_now"]
-            self._page_labels = ws["labels_now"].copy()
-        k, S = ws["k"], ws["S"]
-        H_t = self._scenario_h(plan)
-        W_i, W_e = make_masked_w(self.fl, ws["ws_labels"],
-                                 ws["mask_slab"], H_t)
-        splan = RoundPlan(r, m, ws["ws_labels"], ws["mask_slab"], W_i,
-                          W_e, fault=ws["fault"], H_eff=ws["h_eff"])
-        args = self._resolve_args(program, splan, fuse=True)
-        # device-side forwarding: rows of the previous cohort sampled
-        # again now (their commit is still in flight); padded to a
-        # static length, OOB dst entries drop
-        src = np.zeros(S, np.int64)
-        dst = np.full(S, S, np.int64)
-        prev = p["prev"]
-        if prev is not None:
-            _, si, di = np.intersect1d(prev["cohort"], ws["cohort"],
-                                       assume_unique=True,
-                                       return_indices=True)
-            src[:si.shape[0]] = si
-            dst[:di.shape[0]] = di
-            q_prev, s_prev = prev["q"], prev["s"]
-        else:
-            q_prev = jnp.zeros((1,) + ws["q"].shape[1:], ws["q"].dtype)
-            s_prev = jnp.zeros((1,) + ws["s"].shape[1:], jnp.float32)
-        Y0, M0 = pre_fn(p["refs"],
-                        jnp.asarray(ws["src_labels"], jnp.int32),
-                        ws["q"], ws["s"], q_prev, s_prev,
-                        jnp.asarray(src, jnp.int32),
-                        jnp.asarray(dst, jnp.int32))
-        self.key, k_ = jax.random.split(self.key)
-        fn = self._get_round(
-            "streamed_pop" if self.pop is not None else "streamed",
-            program)
-        Y, M = fn(Y0, M0, k_,
-                  jnp.asarray(ws["didx"], jnp.int32),
-                  jnp.asarray(ws["clients"], jnp.int32),
-                  jnp.asarray(ws["lane"]), args)
-        # page-out on device; D2H starts now, lands at the next drain
-        fault = ws["fault"]
-        ref_lane = np.full(m, -1, np.int64)
-        ref_lane[ws["ws_labels"]] = np.arange(S)
-        down = (np.asarray(fault.cluster_down, bool)
-                if fault is not None else np.zeros(m, bool))
-        upd = (ref_lane >= 0) & ~down
-        lanes = np.where(ref_lane >= 0, ref_lane, 0)
-        refs_new, q_out, s_out = post_fn(Y, M, p["refs"],
-                                         jnp.asarray(upd),
-                                         jnp.asarray(lanes, jnp.int32))
+            if self.pop is None:
+                self.labels = ws["labels_now"]
+                self._page_labels = ws["labels_now"].copy()
+            k, S = ws["k"], ws["S"]
+            H_t = self._scenario_h(plan)
+            W_i, W_e = make_masked_w(self.fl, ws["ws_labels"],
+                                     ws["mask_slab"], H_t)
+            splan = RoundPlan(r, m, ws["ws_labels"], ws["mask_slab"], W_i,
+                              W_e, fault=ws["fault"], H_eff=ws["h_eff"])
+            args = self._resolve_args(program, splan, fuse=True)
+            # device-side forwarding: rows of the previous cohort sampled
+            # again now (their commit is still in flight); padded to a
+            # static length, OOB dst entries drop
+            src = np.zeros(S, np.int64)
+            dst = np.full(S, S, np.int64)
+            prev = p["prev"]
+            if prev is not None:
+                _, si, di = np.intersect1d(prev["cohort"], ws["cohort"],
+                                           assume_unique=True,
+                                           return_indices=True)
+                src[:si.shape[0]] = si
+                dst[:di.shape[0]] = di
+            # page-out on device: the synced lane of each cluster
+            fault = ws["fault"]
+            ref_lane = np.full(m, -1, np.int64)
+            ref_lane[ws["ws_labels"]] = np.arange(S)
+            down = (np.asarray(fault.cluster_down, bool)
+                    if fault is not None else np.zeros(m, bool))
+            upd = (ref_lane >= 0) & ~down
+            lanes = np.where(ref_lane >= 0, ref_lane, 0)
+        with self._span("dispatch"):
+            if prev is not None:
+                q_prev, s_prev = prev["q"], prev["s"]
+            else:
+                q_prev = jnp.zeros((1,) + ws["q"].shape[1:], ws["q"].dtype)
+                s_prev = jnp.zeros((1,) + ws["s"].shape[1:], jnp.float32)
+            Y0, M0 = pre_fn(p["refs"],
+                            jnp.asarray(ws["src_labels"], jnp.int32),
+                            ws["q"], ws["s"], q_prev, s_prev,
+                            jnp.asarray(src, jnp.int32),
+                            jnp.asarray(dst, jnp.int32))
+            self.key, k_ = jax.random.split(self.key)
+            fn = self._get_round(
+                "streamed_pop" if self.pop is not None else "streamed",
+                program)
+            Y, M = fn(Y0, M0, k_,
+                      jnp.asarray(ws["didx"], jnp.int32),
+                      jnp.asarray(ws["clients"], jnp.int32),
+                      jnp.asarray(ws["lane"]), args)
+            # D2H starts now, lands at the next drain
+            refs_new, q_out, s_out = post_fn(Y, M, p["refs"],
+                                             jnp.asarray(upd),
+                                             jnp.asarray(lanes, jnp.int32))
+            for a in (q_out, s_out, refs_new):
+                a.copy_to_host_async()
         p["refs"] = refs_new
-        for a in (q_out, s_out, refs_new):
-            a.copy_to_host_async()
         # drain round r-1 (its D2H overlapped round r's dispatch) and
         # only then stage r+1, so staging sees commits ≤ r-1 and the
         # forwarding delta is exactly cohort r
-        t0 = time.perf_counter()
-        self._drain_pipeline()
-        p["pending"] = {"cohort": ws["cohort"], "k": k,
-                        "q": q_out, "s": s_out, "refs": refs_new}
-        p["prev"] = {"cohort": ws["cohort"], "q": q_out, "s": s_out}
-        p["staged"] = self._stage_pipelined(self._peek_plan(), r + 1)
-        self._page_seconds += time.perf_counter() - t0
+        with self._span("page.wait"):
+            landed = self._land_pending()
+        with self._span("page.commit"):
+            self._commit_landed(landed)
+            p["pending"] = {"cohort": ws["cohort"], "k": k,
+                            "q": q_out, "s": s_out, "refs": refs_new}
+            p["prev"] = {"cohort": ws["cohort"], "q": q_out, "s": s_out}
+        with self._span("page.stage"):
+            p["staged"] = self._stage_pipelined(self._peek_plan(), r + 1)
         self.last_bucket = S
         self._peak_slab = max(self._peak_slab,
                               2 * 4 * S * self._layout.total)

@@ -119,13 +119,14 @@ def _segment_absmax(x, interpret: bool):
         out_specs=pl.BlockSpec((_BLK_ROWS, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Sp, 1), jnp.float32),
         interpret=interpret,
+        name="cold_absmax",
     )(_pad2(x, Sp, wp))
     return out[:S, 0]
 
 
-def _elementwise(kernel, x, scale, out_dtype, interpret: bool):
-    """Run an elementwise (x, per-row scale) -> out kernel over the
-    column-block grid."""
+def _elementwise(kernel, name, x, scale, out_dtype, interpret: bool):
+    """Run an elementwise (x, per-row scale) -> out kernel, named
+    ``name``, over the column-block grid."""
     S, w = x.shape
     Sp, wp = _ceil_to(S, _BLK_ROWS), _ceil_to(w, _BLK_COLS)
     xspec = pl.BlockSpec((_BLK_ROWS, _BLK_COLS), lambda i, j: (i, j))
@@ -138,6 +139,7 @@ def _elementwise(kernel, x, scale, out_dtype, interpret: bool):
         out_specs=xspec,
         out_shape=jax.ShapeDtypeStruct((Sp, wp), out_dtype),
         interpret=interpret,
+        name=name,
     )(_pad2(x, Sp, wp), scale)
     return out[:S, :w]
 
@@ -165,8 +167,8 @@ def encode_rows(rows, codec: str, segments, *, use_pallas=None,
     for off, size in segments:
         seg = rows[:, off:off + size]
         s = jnp.maximum(_segment_absmax(seg, interpret), 1e-12) / 127.0
-        qs.append(_elementwise(_affine_enc_kernel, seg, s, jnp.int8,
-                               interpret))
+        qs.append(_elementwise(_affine_enc_kernel, "cold_quantize", seg,
+                               s, jnp.int8, interpret))
         ss.append(s)
     return jnp.concatenate(qs, axis=1), jnp.stack(ss, axis=1)
 
@@ -184,8 +186,9 @@ def decode_rows(q, scale, codec: str, segments, *, use_pallas=None,
         return _ref.cold_decode_ref(q, scale, codec, segments)
     outs = []
     for j, (off, size) in enumerate(segments):
-        outs.append(_elementwise(_affine_dec_kernel, q[:, off:off + size],
-                                 scale[:, j], jnp.float32, interpret))
+        outs.append(_elementwise(_affine_dec_kernel, "cold_dequantize",
+                                 q[:, off:off + size], scale[:, j],
+                                 jnp.float32, interpret))
     return jnp.concatenate(outs, axis=1)
 
 

@@ -69,6 +69,7 @@ def gossip_mix_flat(W: jax.Array, Y: jax.Array, *, block: int = 2048,
         out_specs=pl.BlockSpec((k, block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((k, nb * block), Y.dtype),
         interpret=interpret,
+        name="gossip_mix",
     )(W, Y)
     return out[:, :T]
 
@@ -123,15 +124,18 @@ def gossip_mix_rows(W, Y: jax.Array, *, block: int = 2048,
     in-place blocked pass (:func:`_mix_rows_blocked`) when W is square,
     else one XLA gemm (the rectangular edge-model projection;
     :func:`repro.kernels.ref.gossip_mix_rows_ref` is the oracle for
-    both)."""
+    both). Everything it lowers to, the kernel with its pad and slice
+    copies, sits under ``jax.named_scope("mix")``."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    if use_pallas or interpret:
-        Wj = jnp.asarray(W, jnp.float32)
-        return gossip_mix_flat(Wj.T, Y, block=block, interpret=interpret)
-    if W.shape[0] == Y.shape[0]:          # square: stream in place
-        return _mix_rows_blocked(W, Y)
-    return _ref.gossip_mix_rows_ref(jnp.asarray(W, jnp.float32), Y)
+    with jax.named_scope("mix"):
+        if use_pallas or interpret:
+            Wj = jnp.asarray(W, jnp.float32)
+            return gossip_mix_flat(Wj.T, Y, block=block,
+                                   interpret=interpret)
+        if W.shape[0] == Y.shape[0]:          # square: stream in place
+            return _mix_rows_blocked(W, Y)
+        return _ref.gossip_mix_rows_ref(jnp.asarray(W, jnp.float32), Y)
 
 
 # ---------------------------------------------------------------------------

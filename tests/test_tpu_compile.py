@@ -5,20 +5,28 @@ system runs it and compiles it with the TPU compiler for one chip of a
 described ``v5e:2x2`` topology: the compiler refuses what Mosaic cannot
 lower (an unaligned slice, an f16 cast inside a kernel, too much VMEM)
 and what does not fit the chip's memory, which interpret mode never
-shows. Shapes:
+shows. The compiled text must also keep the names a profiler trace is
+read by: the kernels' ``name=`` and the ``op_name`` metadata of the
+round's named scopes (``local_steps``, ``mix``, ``page_in``,
+``page_out``). Shapes:
 
-- ``gossip_mix_flat`` at the paper cell's bank, n = 64 rows of the
-  FEMNIST CNN (T = 6,603,710): the square mixing operator and the
-  (64 -> 8) edge-model projection;
-- ``cold_codec`` encode/decode at the population cell's wide MLP
-  (64 -> 2048 -> 32, T = 198,688) on a 16-row slab, int8 and f16, on
-  one chip and row-sharded over the four chips (the sharded streamed
+- ``gossip_mix_rows`` at the paper cell's bank, n = 64 rows of the
+  FEMNIST CNN (T = 6,603,710): the square mixing operator after the
+  local steps' scan (``FLSimulator._train_scan``, a stand-in step) and
+  the (64 -> 8) edge-model projection;
+- the pipelined streamed round's page-in/page-out jits (``paging_fns``: the
+  ``cold_codec`` decode/encode) at the population cell's wide MLP
+  (64 -> 2048 -> 32, T = 198,688) on a 16-row slab, int8 and f16, and
+  the codec row-sharded over the four chips (the sharded streamed
   engine's slab).
 
 The topology is described inside a module fixture, never at import:
 the TPU library may be loaded by one process at a time, and only the
 worker that runs this file loads it.
 """
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,8 +35,10 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 from repro.configs import femnist_cnn
+from repro.core.cefedavg import FLSimulator, paging_fns
+from repro.core.program import LocalSteps
 from repro.kernels import cold_codec
-from repro.kernels.gossip_mix import FlatLayout, gossip_mix_flat
+from repro.kernels.gossip_mix import FlatLayout, gossip_mix_rows
 from repro.models.cnn import init_mlp_classifier
 
 #: HBM of one TPU v5e chip
@@ -81,37 +91,79 @@ def _peak_bytes(compiled) -> int:
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
 
 
+def _op_names(hlo: str):
+    return set(re.findall(r'op_name="([^"]*)"', hlo))
+
+
+def _has_scope(hlo: str, scope: str) -> bool:
+    return any(scope in name.split("/") for name in _op_names(hlo))
+
+
+def _local_step(carry, key):
+    """A stand-in for the SGD-momentum step over the bank rows."""
+    Y, M = carry
+    M = 0.9 * M + Y
+    return (Y - 0.1 * M, M), None
+
+
 @pytest.mark.parametrize("k", [N_PAPER, 8], ids=["square", "edge_projection"])
 def test_gossip_mix_flat_compiles_at_paper_width(one_chip, k):
-    """The mixing kernel at the paper cell's (64, 6,603,710) bank."""
+    """The mixing kernel at the paper cell's (64, 6,603,710) bank; the
+    square operator mixes the bank the local steps' scan left."""
     T = femnist_cnn.PARAMS
-    W = jax.ShapeDtypeStruct((N_PAPER, k), jnp.float32, sharding=one_chip)
-    Y = jax.ShapeDtypeStruct((N_PAPER, T), jnp.float32, sharding=one_chip)
-    compiled = _compile(gossip_mix_flat, W, Y)
-    assert "tpu_custom_call" in compiled.as_text()
+    S = jax.ShapeDtypeStruct
+    W = S((k, N_PAPER), jnp.float32, sharding=one_chip)
+    Y = S((N_PAPER, T), jnp.float32, sharding=one_chip)
+    key = S((2,), jnp.uint32, sharding=one_chip)
+    if k == N_PAPER:
+        def fn(W, Y, key):
+            Y, _ = FLSimulator._train_scan(_local_step, Y, Y, key,
+                                           LocalSteps(tau=2))
+            return gossip_mix_rows(W, Y, use_pallas=True)
+    else:
+        def fn(W, Y, key):
+            return gossip_mix_rows(W, Y, use_pallas=True)
+    compiled = _compile(fn, W, Y, key)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert re.search(r"%gossip_mix\.\d+ = ", hlo)
+    assert _has_scope(hlo, "mix")
+    assert _has_scope(hlo, "local_steps") == (k == N_PAPER)
     assert _peak_bytes(compiled) < V5E_HBM_BYTES
 
 
 @pytest.mark.parametrize("codec", ["int8", "f16"])
 def test_cold_codec_compiles_at_population_width(one_chip, mlp_segments,
-                                                 codec):
-    """Device encode + decode of a wide-MLP slab. int8 runs the Pallas
-    absmax/affine kernels; f16 is an XLA cast that Mosaic never sees."""
+                                                 codec, monkeypatch):
+    """Device decode + encode of a wide-MLP slab, inside the pipelined
+    streamed round's page-in and page-out jits. int8 runs the Pallas absmax/
+    affine kernels; f16 is an XLA cast that Mosaic never sees."""
     T = 198_688
+    m = 8
     nseg = len(mlp_segments) if codec == "int8" else 0
     qdt = jnp.int8 if codec == "int8" else jnp.float16
-    rows = jax.ShapeDtypeStruct((SLAB_ROWS, T), jnp.float32,
-                                sharding=one_chip)
-    q = jax.ShapeDtypeStruct((SLAB_ROWS, T), qdt, sharding=one_chip)
-    scale = jax.ShapeDtypeStruct((SLAB_ROWS, nseg), jnp.float32,
-                                 sharding=one_chip)
-    enc = _compile(lambda r: cold_codec.encode_rows(
-        r, codec, mlp_segments, use_pallas=True), rows)
-    dec = _compile(lambda q, s: cold_codec.decode_rows(
-        q, s, codec, mlp_segments, use_pallas=True), q, scale)
-    for compiled in (enc, dec):
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    rows = S((SLAB_ROWS, T), jnp.float32)
+    q = S((SLAB_ROWS, T), qdt)
+    scale = S((SLAB_ROWS, nseg), jnp.float32)
+    lanes = S((SLAB_ROWS,), jnp.int32)
+    refs = S((m, T), jnp.float32)
+    # this process's backend is the CPU: take the chip's kernels
+    monkeypatch.setattr(cold_codec, "_use_pallas", lambda _: True)
+    pre, post = paging_fns(codec, mlp_segments)
+    dec = pre.lower(refs, lanes, q, scale, q, scale, lanes, lanes).compile()
+    enc = post.lower(rows, rows, refs, S((m,), jnp.bool_),
+                     S((m,), jnp.int32)).compile()
+    kernels = {"int8": ({"cold_dequantize"}, {"cold_absmax",
+                                               "cold_quantize"}),
+               "f16": (set(), set())}[codec]
+    for compiled, scope, names in ((dec, "page_in", kernels[0]),
+                                   (enc, "page_out", kernels[1])):
         hlo = compiled.as_text()
         assert ("tpu_custom_call" in hlo) == (codec == "int8")
+        assert _has_scope(hlo, scope)
+        for name in names:
+            assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
         assert _peak_bytes(compiled) < V5E_HBM_BYTES
     out_q, out_s = jax.eval_shape(lambda r: cold_codec.encode_rows(
         r, codec, mlp_segments, use_pallas=True), rows)
