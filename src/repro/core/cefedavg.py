@@ -126,6 +126,50 @@ def mix(W, params):
     return jax.tree.map(one, params)
 
 
+#: share of the device's memory limit that one chunk of per-row backward
+#: residuals may take. TPU layout padding inflates the residuals' logical
+#: bytes about 4-5x (channel-minor activations pad 32 lanes to 128), and
+#: the slab's (rows, T) buffers (params, momentum, the gradient) need the
+#: rest of the memory.
+ROW_CHUNK_SHARE = 1 / 32
+#: rows of a TPU float32 tile: a chunk of a multiple of 8 rows compiles
+#: to a third of the temporaries of a 9-row one, in a seventh of the
+#: time (72-row slab of the FEMNIST CNN, compiled for a v5e)
+TILE_ROWS = 8
+
+
+def device_bytes_limit() -> Optional[int]:
+    """The memory limit the first local device reports, or None where
+    the backend reports none (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return (stats or {}).get("bytes_limit")
+
+
+def row_residual_bytes(loss_row: Callable, row, x, y) -> int:
+    """Logical bytes of one row's backward residuals: what ``jax.vjp``
+    of ``loss_row`` in its first argument keeps for the backward pass,
+    at the shapes of ``row``, ``x`` and ``y`` (arrays or shape structs)."""
+    res = jax.eval_shape(
+        lambda r, x, y: jax.vjp(lambda r: loss_row(r, x, y), r)[1],
+        row, x, y)
+    return sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(res))
+
+
+def grad_chunk_rows(rows: int, row_bytes: int,
+                    bytes_limit: Optional[int]) -> int:
+    """Rows whose per-row gradients are computed at once: the largest
+    divisor ``c`` of ``rows`` with ``c * row_bytes`` within
+    :data:`ROW_CHUNK_SHARE` of ``bytes_limit``, a multiple of
+    :data:`TILE_ROWS` where one fits (at least 1); all rows where no
+    limit is known."""
+    if not bytes_limit:
+        return rows
+    budget = bytes_limit * ROW_CHUNK_SHARE
+    fits = [c for c in range(1, rows + 1)
+            if rows % c == 0 and c * row_bytes <= budget]
+    return max([c for c in fits if c % TILE_ROWS == 0] or fits or [1])
+
+
 def paging_fns(codec: str, segments, sharding=None):
     """The pipelined streamed round's pre/post jits. The CORE round stays
     the serial streamed round's own compiled lowering (``_get_round``) — f32
@@ -314,6 +358,8 @@ class FLSimulator:
         # cumulative host seconds per span of ``step_round``
         # (``core/spans.py``: round, assemble, dispatch, page.*)
         self.host_seconds: Dict[str, float] = {}
+        # rows per per-row gradient pass, per traced (lowering, slab rows)
+        self.grad_chunk_rows: Dict = {}
         self.last_bucket = n   # cohort capacity used by the latest round
         # -- round schedule (RoundProgram IR) -------------------------------
         # every engine round is a lowering of a RoundProgram; the static
@@ -556,9 +602,10 @@ class FLSimulator:
         return global_round
 
     # -- program lowering: flat ModelBank engine ----------------------------
-    def _flat_helpers(self):
+    def _flat_helpers(self, lowering: str):
         """Local-step factory shared by the flat rounds; the per-row grad
-        closure materializes pytree views only inside the apply call."""
+        closure materializes pytree views only inside the apply call.
+        ``lowering`` names the round in :attr:`grad_chunk_rows`."""
         n = self.sched.n
         N = self.data["xs"].shape[1]
         layout = self._layout
@@ -566,6 +613,23 @@ class FLSimulator:
         def loss_row(row, x, y):
             return self._loss(layout.unflatten_one(row), x, y)
         grad_row = jax.grad(loss_row)
+
+        def chunk_rows(xs, ys, rows):
+            """Rows per gradient pass (:func:`grad_chunk_rows`): few
+            enough that one pass's backward residuals fit beside the
+            slab, so XLA need not rematerialise the forward pass. A
+            row-sharded slab keeps one pass: a contiguous chunk would
+            span its shards."""
+            if self._streamed and self._slab_sharding is not None:
+                return rows
+            S = jax.ShapeDtypeStruct
+            r = row_residual_bytes(
+                loss_row, S((layout.total,), jnp.float32),
+                S((self.batch,) + xs.shape[2:], xs.dtype),
+                S((self.batch,), ys.dtype))
+            c = grad_chunk_rows(rows, r, device_bytes_limit())
+            self.grad_chunk_rows[(lowering, rows)] = c
+            return c
 
         def make_local_step(xs, ys, act2d, gather=None, tau_dev=None,
                             lr_scale=1.0, fold_ids=None):
@@ -580,6 +644,8 @@ class FLSimulator:
             composition; ``tau_dev`` (adaptive programs) freezes each
             row past its per-device step cutoff."""
             lr = self.lr * lr_scale
+            rows = act2d.shape[0]
+            c = chunk_rows(xs, ys, rows)
 
             def local_step(carry, xs_):
                 if tau_dev is not None:
@@ -598,7 +664,12 @@ class FLSimulator:
                         idx = idx[gather]
                 xb = jax.vmap(lambda x, i: x[i])(xs, idx)
                 yb = jax.vmap(lambda y, i: y[i])(ys, idx)
-                G = jax.vmap(grad_row)(Y, xb, yb)
+                if c == rows:
+                    G = jax.vmap(grad_row)(Y, xb, yb)
+                else:
+                    with jax.named_scope("row_grads"):
+                        G = jax.lax.map(lambda a: grad_row(*a),
+                                        (Y, xb, yb), batch_size=c)
                 M = jnp.where(act, self.momentum * M + G, M)
                 Y = jnp.where(act, Y - lr * M, Y)
                 return (Y, M), None
@@ -636,7 +707,7 @@ class FLSimulator:
         n = self.sched.n
         comp, dp = self.compression, self.dp
         xs, ys = self.data["xs"], self.data["ys"]
-        make_local_step = self._flat_helpers()
+        make_local_step = self._flat_helpers("flat")
         segments = self._layout.segments
         plans = prg.lowering_plan(program, fuse=True)
         runs = prg.block_runs(plans)
@@ -715,7 +786,7 @@ class FLSimulator:
         operators move every device's row. Traced once per cohort bucket
         (static shapes under jit). Upload programs never dispatch here."""
         xs, ys = self.data["xs"], self.data["ys"]
-        make_local_step = self._flat_helpers()
+        make_local_step = self._flat_helpers("compact")
         plans = prg.lowering_plan(program, fuse=True)
         runs = prg.block_runs(plans)
         nblocks = len(plans)
@@ -778,7 +849,7 @@ class FLSimulator:
         populations. Traced once per slab bucket."""
         xs = jnp.asarray(self.data["xs"])
         ys = jnp.asarray(self.data["ys"])
-        make_local_step = self._flat_helpers()
+        make_local_step = self._flat_helpers("streamed")
         plans = prg.lowering_plan(program, fuse=True)
         runs = prg.block_runs(plans)
         nblocks = len(plans)

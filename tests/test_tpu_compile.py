@@ -14,6 +14,10 @@ round's named scopes (``local_steps``, ``mix``, ``page_in``,
   FEMNIST CNN (T = 6,603,710): the square mixing operator after the
   local steps' scan (``FLSimulator._train_scan``, a stand-in step) and
   the (64 -> 8) edge-model projection;
+- the flat round's local steps at the paper cell's bank, with the
+  per-row gradients in the row chunks the chip's memory limit gives
+  (``core/cefedavg.py`` ``grad_chunk_rows``), at ``highest``: no
+  ``.remat`` instruction, the working set well inside the chip;
 - the pipelined streamed round's page-in/page-out jits (``paging_fns``: the
   ``cold_codec`` decode/encode) at the population cell's wide MLP
   (64 -> 2048 -> 32, T = 198,688) on a 16-row slab, int8 and f16, and
@@ -35,14 +39,18 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 from repro.configs import femnist_cnn
+from repro.core import cefedavg
 from repro.core.cefedavg import FLSimulator, paging_fns
 from repro.core.program import LocalSteps
 from repro.kernels import cold_codec
 from repro.kernels.gossip_mix import FlatLayout, gossip_mix_rows
-from repro.models.cnn import init_mlp_classifier
+from repro.models.cnn import (apply_femnist_cnn, init_femnist_cnn,
+                              init_mlp_classifier)
 
 #: HBM of one TPU v5e chip
 V5E_HBM_BYTES = 16 * 2 ** 30
+#: the memory limit a TPU v5e chip reports usable (15.75 GiB)
+V5E_BYTES_LIMIT = int(15.75 * 2 ** 30)
 N_PAPER = 64
 SLAB_ROWS = 16
 
@@ -130,6 +138,41 @@ def test_gossip_mix_flat_compiles_at_paper_width(one_chip, k):
     assert _has_scope(hlo, "mix")
     assert _has_scope(hlo, "local_steps") == (k == N_PAPER)
     assert _peak_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_local_steps_compile_without_remat_at_paper_width(one_chip,
+                                                         monkeypatch):
+    """Two local steps of the paper cell (64 rows, batch 50, the FEMNIST
+    CNN at ``highest``) with the row chunks the rule picks for a v5e:
+    XLA rematerialises nothing, and the temporaries stay under 6 GB
+    (the all-rows ``vmap`` took 14.1 GB and rematerialised six
+    instructions)."""
+    n, N, image = N_PAPER, 64, femnist_cnn.IMAGE
+    # a streamed simulator holds no (64, T) bank on the host; its
+    # local-step factory is the flat round's
+    data = {"xs": np.zeros((n, N) + image, np.float32),
+            "ys": np.zeros((n, N), np.int32)}
+    sim = FLSimulator(init_femnist_cnn, apply_femnist_cnn, femnist_cnn.FL,
+                      data, batch_size=50, streaming=True)
+    # this process's backend is the CPU: state the chip's limit
+    monkeypatch.setattr(cefedavg, "device_bytes_limit",
+                        lambda: V5E_BYTES_LIMIT)
+    make_local_step = sim._flat_helpers("flat")
+
+    def fn(Y, M, key, xs, ys):
+        step = make_local_step(xs, ys, jnp.ones((n, 1), bool))
+        return FLSimulator._train_scan(step, Y, M, key, LocalSteps(tau=2))
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    bank = S((n, femnist_cnn.PARAMS), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(
+            bank, bank, S((2,), jnp.uint32),
+            S((n, N) + image, jnp.float32), S((n, N), jnp.int32)).compile()
+    assert sim.grad_chunk_rows == {("flat", n): 8}
+    hlo = compiled.as_text()
+    assert ".remat" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+    assert _has_scope(hlo, "local_steps") and _has_scope(hlo, "row_grads")
 
 
 @pytest.mark.parametrize("codec", ["int8", "f16"])
