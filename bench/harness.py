@@ -46,6 +46,9 @@ MIN_ROUNDS = 3
 TRACE_SECONDS = 4.0
 #: rounds of a traced run whose host time is read with the device idle
 PROBE_ROUNDS = 5
+#: the numbers :func:`compare` gives; a cell's workload file names each
+#: either under ``limits`` or, with the reason, under ``not_compared``
+NUMBERS = ("mom_gap", "mom_row_gap", "mom_rows_wrong", "dparam_gap")
 
 
 def log(msg: str) -> None:
@@ -144,6 +147,7 @@ class CompileEvents:
     def __init__(self):
         import jax
         self.counts = collections.Counter()
+        self.compiles: List = []     # (seconds, function) per backend compile
         jax.monitoring.register_event_listener(self._event)
         jax.monitoring.register_event_duration_secs_listener(self._duration)
 
@@ -151,12 +155,19 @@ class CompileEvents:
         if event in self.NAMES:
             self.counts[self.NAMES[event]] += 1
 
-    def _duration(self, event: str, duration: float, **_):
+    def _duration(self, event: str, duration: float, **kw):
         if event in self.NAMES:
             self.counts[self.NAMES[event]] += 1
+        if self.NAMES.get(event) == "compiles":
+            self.compiles.append((round(duration, 3), kw.get("fun_name")))
 
     def snapshot(self) -> Dict[str, int]:
         return {k: self.counts[k] for k in self.NAMES.values()}
+
+    def longest(self, k: int = 3) -> List:
+        """The ``k`` longest backend compiles so far: a persistent-cache
+        miss is one of them."""
+        return sorted(self.compiles, key=lambda c: c[0], reverse=True)[:k]
 
 
 # -- the cell's engines ----------------------------------------------------------
@@ -306,14 +317,50 @@ def compared_leaves(ref_mom: Dict[str, float]) -> List[str]:
     return [k for k, v in ref_mom.items() if v >= 1e-3 * med]
 
 
+def row_gap(prog_rows, ref_rows) -> float:
+    """Median trained row's gap between the program's norm and the
+    reference's, over the reference's (rows the reference leaves at
+    nought have not trained)."""
+    p, r = np.asarray(prog_rows), np.asarray(ref_rows)
+    t = r > 0
+    return float(np.median(np.abs(p[t] - r[t]) / r[t]))
+
+
+def rows_wrong(prog_rows, ref_rows) -> int:
+    """Rows whose momentum after round 1 is nought on one side only: a
+    row the program stepped that the reference left alone, or the
+    reverse (an update scattered to the wrong row, or lost)."""
+    return int(np.sum((np.asarray(prog_rows) > 0)
+                      != (np.asarray(ref_rows) > 0)))
+
+
 def compare(prog: Dict, ref: Dict) -> Dict[str, float]:
-    """The numbers that decide ``correct``: ``mom_gap`` (momentum after
-    round 1) and ``dparam_gap`` (change of the parameters after each
-    checked round, the worst round)."""
+    """The numbers a check can compare (:data:`NUMBERS`): ``mom_gap``
+    (momentum after round 1, the worst leaf), ``mom_row_gap`` (momentum
+    after round 1, the median trained row), ``mom_rows_wrong`` (rows
+    stepped in round 1 by one side only) and ``dparam_gap`` (change of
+    the parameters after each checked round, the worst round's worst
+    leaf). A workload's ``limits`` name the ones its runs compare."""
     leaves = compared_leaves(ref["mom1"])
     return {"mom_gap": gap(prog["mom1"], ref["mom1"], leaves),
+            "mom_row_gap": row_gap(prog["mom1_rows"], ref["mom1_rows"]),
+            "mom_rows_wrong": rows_wrong(prog["mom1_rows"],
+                                         ref["mom1_rows"]),
             "dparam_gap": max(gap(p, r, leaves) for p, r in
                               zip(prog["dparam"], ref["dparam"]))}
+
+
+def compared(workload: Dict) -> List[str]:
+    """The numbers a cell's runs compare: its ``limits``. Every other
+    number of :data:`NUMBERS` has to be under ``not_compared`` with the
+    reason, so that none drops out of a check unseen."""
+    limits, skip = set(workload["limits"]), set(workload.get(
+        "not_compared", {}))
+    if limits & skip or limits | skip != set(NUMBERS):
+        raise ValueError(
+            f"workload {workload['name']!r}: limits {sorted(limits)} and "
+            f"not_compared {sorted(skip)} must split {list(NUMBERS)}")
+    return sorted(limits)
 
 
 # -- one run -------------------------------------------------------------------------
@@ -344,13 +391,13 @@ def prepare(cell: Cell, seed: int):
 
 def checked_rounds(eng: Engine, cell: Cell, model_mod):
     """The cell's first rounds, through the window's own ``step_round``:
-    returns the per-leaf norms the check compares (momentum after round
-    1, change of the parameters after each round) and the rounds'
-    seconds."""
+    returns the norms the check compares (momentum after round 1, per
+    leaf and per row; change of the parameters after each round, per
+    leaf) and the rounds' seconds."""
     import jax
     import jax.numpy as jnp
 
-    from bench.reference import leaf_names
+    from bench.reference import leaf_names, row_norms
     names = leaf_names(jax.eval_shape(
         model_mod.system(cell.config["model_sizes"])[0],
         jax.random.PRNGKey(0)))
@@ -366,6 +413,7 @@ def checked_rounds(eng: Engine, cell: Cell, model_mod):
         if r == 0:
             mom = jnp.asarray(eng.momentum_rows(plan))
             prog["mom1"] = dict(zip(names, map(float, norms(mom, None))))
+            prog["mom1_rows"] = row_norms([mom])
             del mom
         prog["dparam"].append(dict(zip(names, map(float, norms(
             jnp.asarray(eng.params_rows()), row0)))))
@@ -392,9 +440,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     from bench import counts
     from bench import trace as tr
     cell = cell or resolve(name, bench)
+    cfg, wl = cell.config, cell.workload
+    compared(wl)
     events = CompileEvents()
     log(f"[bench] cell={cell.name} seed={seed}")
-    cfg, wl = cell.config, cell.workload
     eng, data, model_mod, pseed = prepare(cell, seed)
     sim = eng.sim
     k_check = int(wl["check_rounds"])
@@ -406,10 +455,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     n_window = max(MIN_ROUNDS, int(round(seconds / est)))
     c_setup = events.snapshot()
     log(f"[bench] warm-up {warm} round_estimate_s={est:.4f} "
-        f"window_rounds={n_window} compile_events={c_setup}")
+        f"checked_rounds_s={[round(t, 3) for t in warm_times]} "
+        f"window_rounds={n_window} compile_events={c_setup} "
+        f"longest_compiles={events.longest()}")
 
     # the window
     page0 = eng.page_seconds()
+    host0 = dict(sim.host_seconds)
     stamps, rows = [], 0
     t_win = time.perf_counter()
     setup_s = t_win - t_start
@@ -438,6 +490,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     }
     log(f"[bench] window rounds={n_window} window_s={run['window_s']:.4f} "
         f"compile_events_in_window={c_window} peak_bytes_in_use={peak}")
+    # where a slow window lost its time: each round's interval, and the
+    # program's host spans over the window
+    spans = {k: round(v - host0.get(k, 0.0), 4)
+             for k, v in sim.host_seconds.items()}
+    log(f"[bench] window round_intervals_s="
+        f"{np.round(np.diff([t_win] + stamps + [t_end]), 4).tolist()} "
+        f"host_span_s={spans}")
     result_device = {"platform": dev.platform, "kind": dev.device_kind,
                      "count": len(jax.devices()),
                      "memory_peak_bytes": peak}
@@ -474,8 +533,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     numbers = reference_numbers(cell, data, pseed, model_mod, prog)
     ref_s = time.perf_counter() - t0
     limits = wl["limits"]
-    checks = {k: {"value": v, "limit": limits[k]}
-              for k, v in numbers.items()}
+    checks = {k: {"value": numbers[k], "limit": v}
+              for k, v in limits.items()}
     correct = all(v["value"] <= v["limit"] for v in checks.values())
     out = {"correct": correct, "attempted": n_window, "failed": 0,
            "metrics": metrics, "device": result_device}

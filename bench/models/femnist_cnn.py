@@ -22,6 +22,9 @@ import jax
 import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
+#: a CPU rehearsal's sizes: 8x8 images, channel and hidden widths as
+#: published
+TINY = {"image": 8}
 #: three bfloat16 passes (hi*hi + hi*lo + lo*hi, accumulated in float32)
 #: in every product of the forward and of the backward pass, emulated,
 #: so that the check's control computes alike on every backend

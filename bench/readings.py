@@ -6,9 +6,13 @@ runs do not run this.
 - the control's (the upper reading): the reference computed in
   bfloat16, put in the program's place;
 - faults planted in the reference put in the program's place: half of
-  each batch left out, and the mixing left out. A state left unchanged
-  reads 1 by the measure (its change of the parameters is nought) and
-  needs no run.
+  each batch left out, the mixing left out, and one device's local
+  steps written to another device of its edge server. A state left
+  unchanged reads 1 by the measure (its change of the parameters is
+  nought) and needs no run.
+
+Each line also carries the per-row momentum norms after round 1 of both
+sides (``rows``: program or stand-in, then reference).
 
   python bench/readings.py --workload <cell> --seeds 1,2,3 --controls 4,5,6
 
@@ -34,7 +38,8 @@ from bench.reference import Reference  # noqa: E402
 
 class HalfBatch(Reference):
     """The reference with half of each batch left out: the loss is the
-    mean over the first half."""
+    mean over the first half. Axis 1 of ``(rows, batch, ...)`` is the
+    batch for images and token sequences alike."""
 
     def _row_grads(self, Y, xb, yb):
         h = xb.shape[1] // 2
@@ -54,7 +59,40 @@ class NoMixing(Reference):
         return jax.tree.map(lambda y: y[first], Y)
 
 
-FAULTS = {"half_batch": HalfBatch, "no_mixing": NoMixing}
+def moved_row(mask: np.ndarray, per: int):
+    """The first participating row of ``mask`` and an idle row of its
+    edge server (rows ``[c*per, (c+1)*per)``), or None where none is
+    idle."""
+    for i in np.nonzero(mask > 0)[0]:
+        c = int(i) // per
+        idle = [j for j in range(c * per, (c + 1) * per) if mask[j] <= 0]
+        if idle:
+            return int(i), idle[0]
+    return None
+
+
+class WrongRows(Reference):
+    """The reference with one device's local steps gathered from and
+    scattered to another, idle, device of its edge server, while the
+    mixing averages the devices that participate."""
+
+    def _stepped(self, mask):
+        out = np.array(mask)
+        pair = moved_row(out, self.per)
+        if pair is not None:
+            out[list(pair)] = out[list(pair[::-1])]
+        return out
+
+
+FAULTS = {"half_batch": HalfBatch, "no_mixing": NoMixing,
+          "wrong_rows": WrongRows}
+
+
+def rows(prog: dict, ref: dict) -> list:
+    """Per-row momentum norms after round 1: the program's (or the
+    stand-in's), then the reference's."""
+    return [np.asarray(prog["mom1_rows"]).tolist(),
+            np.asarray(ref["mom1_rows"]).tolist()]
 
 
 def program_reading(cell, seed: int) -> dict:
@@ -67,9 +105,11 @@ def program_reading(cell, seed: int) -> dict:
     gc.collect()
     jax.clear_caches()
     t0 = time.perf_counter()
-    numbers = harness.reference_numbers(cell, data, pseed, model_mod, prog)
-    return {"seed": seed, "kind": "program", "numbers": numbers,
-            "round_s": times, "reference_s": time.perf_counter() - t0}
+    ref = Reference(cell.config, cell.traffic, data, pseed, model_mod).run(
+        int(cell.workload["check_rounds"]))
+    return {"seed": seed, "kind": "program",
+            "numbers": harness.compare(prog, ref), "round_s": times,
+            "reference_s": time.perf_counter() - t0, "rows": rows(prog, ref)}
 
 
 #: the control of each stated matmul precision: the reference computed
@@ -98,9 +138,10 @@ def control_readings(cell, seed: int, kinds=None):
             stand_in = Reference(*args, precision=model_mod.BF16_3X)
         else:
             stand_in = FAULTS[kind](*args)
-        numbers = harness.compare(stand_in.run(k), ref)
-        out.append({"seed": seed, "kind": kind, "numbers": numbers,
-                    "reference_s": ref_s})
+        got = stand_in.run(k)
+        out.append({"seed": seed, "kind": kind,
+                    "numbers": harness.compare(got, ref),
+                    "reference_s": ref_s, "rows": rows(got, ref)})
     return out
 
 

@@ -18,6 +18,29 @@ per local step. A step draws ``batch`` sample indices per row:
 ``randint(fold_in(step_key, client_id), (batch,))`` per client of a
 streamed population, on data shard ``client_id % shards``.
 
+The loss of a row is the mean cross-entropy over every label element:
+with logits ``(B, ..., V)`` and integer labels ``(B, ...)``,
+
+    picked = take_along_axis(logits, y[..., None], -1)[..., 0]
+    loss = mean(logsumexp(logits, -1) - picked)
+
+For a classifier, ``(B, C)`` logits and ``(B,)`` labels, this is the
+per-sample loss in the same expression and order as
+``take_along_axis(logits, y[:, None], -1)[:, 0]``, bit for bit; for a
+next-token model, ``(B, S, V)`` logits and ``(B, S)`` labels, it is the
+mean over every position of every sequence. Inputs that are integers
+(token ids) stay int32 whatever the reference's ``dtype``; only
+floating-point inputs (images) are cast to it.
+
+Participation: a deployment whose traffic states no ``scenario`` trains
+every device every round. A resident one with a ``scenario`` samples,
+each round ``r`` and in each edge server ``c``, ``ceil(sample_fraction
+* devices_per_cluster)`` of the server's devices without replacement,
+from ``default_rng(SeedSequence([seed, r, 2, c]))``, and thins them by
+``dropout_prob``; if nobody is left anywhere, the first device sampled
+trains. Only the sampled devices step; every device takes its edge's
+average of the sampled ones at each boundary.
+
 The control runs this same code in ``bfloat16``: parameters, momentum,
 activations and mixing all in the precision below the configuration's.
 """
@@ -108,6 +131,25 @@ def population_cohort(seed: int, r: int, m: int, size: int, per: int,
     return ids if ids.size else np.asarray([first], np.int64)
 
 
+def sampled_mask(seed: int, r: int, m: int, per: int, fraction: float,
+                 dropout: float) -> np.ndarray:
+    """A resident federation's participation in round ``r``: (m * per,)
+    0/1, edge server c's devices being [c*per, (c+1)*per)."""
+    mask = np.zeros(m * per)
+    first = None
+    for c in range(m):
+        rng = np.random.default_rng(np.random.SeedSequence(
+            [int(seed), int(r), 2, int(c)]))
+        k = max(1, int(math.ceil(fraction * per)))
+        picks = c * per + rng.choice(per, size=k, replace=False)
+        if first is None:
+            first = int(picks[0])
+        mask[picks[rng.random(k) >= dropout]] = 1.0
+    if not mask.any():
+        mask[first] = 1.0
+    return mask
+
+
 # -- per-leaf statistics -----------------------------------------------------
 
 
@@ -130,13 +172,32 @@ def leaf_norms(tree, sub=None) -> Dict[str, float]:
     return out
 
 
+@jax.jit
+def _row_squares(a):
+    a = a.astype(jnp.float32)
+    return jnp.sum(jnp.square(a.reshape(a.shape[0], -1)), axis=1)
+
+
+def row_norms(tree) -> np.ndarray:
+    """Frobenius norm of each row (axis 0), over every leaf."""
+    return np.sqrt(sum(np.asarray(_row_squares(a), np.float64)
+                       for a in jax.tree.leaves(tree)))
+
+
 # -- the reference trainer ---------------------------------------------------
+
+def cross_entropy(logits, y):
+    """Mean cross-entropy of ``logits`` (..., V) over every element of
+    the integer labels ``y`` (the logits' shape less its last axis)."""
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    return jnp.mean(lse - jnp.take_along_axis(
+        logits, y[..., None], axis=-1)[..., 0])
 
 
 class Reference:
     """CE-FedAvg from the configuration: ``run(rounds)`` returns the
-    per-leaf norms the check compares (momentum after round 1, change
-    of the parameters after each round)."""
+    norms the check compares (momentum after round 1, per leaf and per
+    row; change of the parameters after each round, per leaf)."""
 
     def __init__(self, cfg: Dict, traffic: Dict, data: Dict, seed: int,
                  model_mod, dtype=jnp.float32, precision=HIGHEST):
@@ -148,7 +209,9 @@ class Reference:
         self.tau, self.q, self.pi = fed["tau"], fed["q"], fed["pi"]
         self.lr, self.mu, self.batch = opt["lr"], opt["momentum"], opt["batch"]
         self.H = metropolis(ring(self.m))
-        self.xs = jnp.asarray(data["xs"], dtype)
+        xs = np.asarray(data["xs"])
+        self.xs = jnp.asarray(xs, dtype if np.issubdtype(
+            xs.dtype, np.floating) else jnp.int32)
         self.ys = jnp.asarray(data["ys"], jnp.int32)
         self.N = int(self.xs.shape[1])
         self.p0 = model_mod.ref_init(jax.random.PRNGKey(seed), self.model,
@@ -158,15 +221,13 @@ class Reference:
                 Y, M, self._row_grads(Y, xb, yb), act),
             donate_argnums=(0, 1))
 
-    # one row at a time: grads of the mean cross-entropy per row
+    # one row at a time: grads of the row's loss on its batch
     def _row_grads(self, Y, xb, yb):
         apply = self.mod.ref_apply
 
         def loss(p, x, y):
-            logits = apply(p, x, self.precision).astype(jnp.float32)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            return jnp.mean(lse - jnp.take_along_axis(
-                logits, y[:, None], axis=-1)[:, 0])
+            return cross_entropy(
+                apply(p, x, self.precision).astype(jnp.float32), y)
         return jax.lax.map(lambda a: jax.grad(loss)(*a), (Y, xb, yb))
 
     def _sgd(self, Y, M, G, act):
@@ -182,6 +243,11 @@ class Reference:
             a = act.reshape((-1,) + (1,) * (y.ndim - 1))
             return jnp.where(a, (y - self.lr * m).astype(dt), y)
         return jax.tree.map(upd_y, Y, M), M
+
+    def _stepped(self, mask: np.ndarray) -> np.ndarray:
+        """The rows that take the local steps of a round in which the
+        devices of ``mask`` participate: those devices."""
+        return mask
 
     def _mix(self, W, Y):
         Wj = jnp.asarray(W, self.dtype)
@@ -200,10 +266,7 @@ class Reference:
     def _run_resident(self, rounds: int) -> Dict:
         n = self.m * self.per
         labels = np.repeat(np.arange(self.m), self.per)
-        assert self.traffic.get("scenario") is None, "full participation"
-        mask = np.ones(n)
-        W_intra, W_inter = operators(labels, mask, self.H, self.pi)
-        act = jnp.ones(n, bool)
+        sc = self.traffic.get("scenario")
         Y = jax.tree.map(lambda a: jnp.tile(a[None], (n,) + (1,) * a.ndim),
                          self.p0)
         M = jax.tree.map(jnp.zeros_like, Y)
@@ -211,6 +274,11 @@ class Reference:
         out = {"dparam": []}
         rows = jnp.arange(n)
         for r in range(rounds):
+            mask = np.ones(n) if sc is None else sampled_mask(
+                self.seed, r, self.m, self.per, sc["sample_fraction"],
+                sc["dropout_prob"])
+            W_intra, W_inter = operators(labels, mask, self.H, self.pi)
+            act = jnp.asarray(self._stepped(mask) > 0)
             key, k_round = jax.random.split(key)
             for b, k_block in enumerate(jax.random.split(k_round, self.q)):
                 for k_step in jax.random.split(k_block, self.tau):
@@ -224,6 +292,7 @@ class Reference:
                     Y = self._mix(W_inter, Y)
             if r == 0:
                 out["mom1"] = leaf_norms(M)
+                out["mom1_rows"] = row_norms(M)
             out["dparam"].append(leaf_norms(Y, jax.tree.map(
                 lambda a: a[None], self.p0)))
         return out
@@ -296,6 +365,9 @@ class Reference:
                     float(np.sqrt(np.sum(np.square(
                         q.astype(np.float32) * s[:, None]))))
                     for q, s in enc)))
+                out["mom1_rows"] = np.sqrt(sum(
+                    np.sum(np.square(q.astype(np.float32) * s[:, None]), 1)
+                    for q, s in enc))
             out["dparam"].append(leaf_norms(refs, jax.tree.map(
                 lambda a: a[None], self.p0)))
         return out

@@ -13,7 +13,9 @@ from bench.tests.tiny import tiny_cell
 @pytest.mark.parametrize("name,precision", [
     ("femnist_paper_full", "default"), ("femnist_paper_full", "highest"),
     ("femnist_xdevice_paging", "default"),
-    ("femnist_xdevice_paging", "highest")])
+    ("femnist_xdevice_paging", "highest"),
+    ("femnist_paper_sampled25", "default"),
+    ("femnist_paper_sampled25", "highest")])
 def test_control_reads_farther_from_the_reference_than_the_program(
         name, precision):
     cell = tiny_cell(name)

@@ -13,7 +13,8 @@ from bench import harness
 from bench.tests.tiny import tiny_cell
 
 ROOT = harness.ROOT
-CELLS = ["femnist_paper_full", "femnist_xdevice_paging"]
+CELLS = ["femnist_paper_full", "femnist_xdevice_paging",
+         "femnist_paper_sampled25"]
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -23,7 +24,7 @@ def test_run_is_correct_and_reports_its_end_to_end_metrics(name):
                            t_start=time.perf_counter(), cell=cell)
     assert out["correct"] is True
     assert list(out)[-1] == "checks"
-    assert set(out["checks"]) == {"mom_gap", "dparam_gap"}
+    assert set(out["checks"]) == set(cell.workload["limits"])
     assert out["attempted"] >= harness.MIN_ROUNDS and out["failed"] == 0
     assert set(out["metrics"]) == {m["name"] for m in cell.end_to_end}
     assert all(v["value"] > 0 for v in out["metrics"].values())

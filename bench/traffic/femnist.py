@@ -18,6 +18,8 @@ from typing import Dict, List
 
 import numpy as np
 
+#: a CPU rehearsal's data: a few samples per shard
+TINY = {"train_samples": 128, "test_samples": 16, "samples_per_device": 16}
 #: class means are fixed, so train and test draws share one task
 MEANS_SEED = 1234
 
